@@ -2,13 +2,19 @@
 window and logit softcap.
 
 Tiling: grid (B, H, n_q, n_kv), n_kv innermost with "arbitrary" semantics so
-the (m, l, acc) accumulators live in VMEM scratch across kv steps.  Blocks:
+the (m, l, acc) accumulators live in VMEM scratch across kv steps.  The
+kernel sees head-major views q (B, H, Sq, Dh) and k/v (B, KVH, Skv, Dh), so
+every block's last two dimensions are (bq, Dh) or (bk, Dh): the TPU compiler
+tiles the last two dimensions of a block by (8, 128), and a one-head slice
+of the (S, H, Dh) layout would put a 1 in the sublane dimension.  Blocks:
 q (bq, Dh), k/v (bk, Dh) per kv-head (GQA via h -> h // group index map).
 MXU-aligned: bq, bk multiples of 128 when the sequence allows; accumulation
 in fp32.  VMEM working set/step: bq·Dh + 2·bk·Dh + bq·bk (fp32 scores)
 ≈ 128·128·4·4 B ≈ 256 KiB at the default blocks — comfortably inside VMEM.
 
-Validated against kernels/ref.py oracles in interpret mode (CPU).
+Validated against kernels/ref.py oracles in interpret mode (CPU), compiled
+for a described v5e chip in tests/test_tpu_compile.py, and checked against
+the jnp attention on the chip by chip_smoke.py.
 """
 from __future__ import annotations
 
@@ -18,12 +24,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU compiler params are optional (absent in interpret mode)
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -50,9 +51,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale     # (bq, Dh)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)             # (bk, Dh)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32) * scale           # (bq, Dh)
+        k = k_ref[0, 0].astype(jnp.float32)                   # (bk, Dh)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if softcap is not None:
@@ -65,24 +66,22 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         if window is not None:
             mask &= k_pos > (q_pos - window)
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
+        m_prev = m_ref[...]                                   # (bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         m_safe = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
-        p = jnp.where(mask, jnp.exp(s - m_safe[:, None]), 0.0)
+        p = jnp.where(mask, jnp.exp(s - m_safe), 0.0)
         alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0,
                           jnp.exp(m_prev - m_safe))
-        l_new = l_ref[:, 0] * alpha + p.sum(axis=1)
-        acc = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[...] = m_new[:, None]
-        l_ref[...] = l_new[:, None]
-        acc_ref[...] = acc
+        m_ref[...] = m_new
 
     @pl.when(ki == n_kv - 1)
     def _finish():
-        l = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -97,14 +96,11 @@ def flash_attention(
     causal: bool = True,
     window: Optional[int] = None,
     softcap: Optional[float] = None,
-    kv_lens=None,                 # unsupported in the kernel (dense prefill)
-    q_offset: int = 0,
     q_block: int = 128,
     kv_block: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
-    assert kv_lens is None and q_offset == 0, \
-        "kernel path is dense prefill; use ops impl='jnp' otherwise"
+    """Dense prefill: query i sits at key position i (no kv lengths)."""
     B, Sq, H, Dh = q.shape
     _, Skv, KVH, _ = k.shape
     G = H // KVH
@@ -118,37 +114,28 @@ def flash_attention(
         _kernel, causal=causal, window=window, softcap=softcap,
         bq=bq, bk=bk, n_kv=n_kv, scale=Dh ** -0.5)
 
-    kwargs = {}
-    if _HAS_PLTPU and not interpret:
-        try:
-            kwargs["compiler_params"] = pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "parallel",
-                                     "arbitrary"))
-        except Exception:
-            pass
-    if _HAS_PLTPU:
-        scratch_shapes = [
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, Dh), jnp.float32),
-        ]
-    else:  # pragma: no cover
-        raise RuntimeError("pallas tpu backend required")
-
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, 1, Dh), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, bk, 1, Dh),
-                         lambda b, h, qi, ki, g=G: (b, ki, h // g, 0)),
-            pl.BlockSpec((1, bk, 1, Dh),
-                         lambda b, h, qi, ki, g=G: (b, ki, h // g, 0)),
+            pl.BlockSpec((1, 1, bq, Dh), lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, bk, Dh),
+                         lambda b, h, qi, ki, g=G: (b, h // g, ki, 0)),
+            pl.BlockSpec((1, 1, bk, Dh),
+                         lambda b, h, qi, ki, g=G: (b, h // g, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, Dh),
-                               lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq, H, Dh), q.dtype),
-        scratch_shapes=scratch_shapes,
+        out_specs=pl.BlockSpec((1, 1, bq, Dh),
+                               lambda b, h, qi, ki: (b, h, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq, Dh), q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, Dh), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
-        **kwargs,
-    )(q, k, v)
+    )(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+      v.transpose(0, 2, 1, 3))
+    return out.transpose(0, 2, 1, 3)

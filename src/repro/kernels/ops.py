@@ -1,18 +1,19 @@
 """Jit'd dispatch wrappers around the compute hot-spots.
 
 Every model-layer call site goes through this module. The implementation is
-chosen by (in priority order): an explicit ``impl=`` argument, the module
-default set via :func:`set_default_impl`, else by backend — Pallas kernels on
-TPU, the memory-sane jnp paths elsewhere (CPU smoke tests and the multi-pod
-dry-run; Pallas TPU kernels cannot lower on the CPU backend, and running them
-in interpret mode inside a 512-way SPMD program would be meaningless).
+chosen by (in priority order): an explicit ``impl=`` argument, the default
+set by the innermost :func:`default_impl` block, else by backend — Pallas
+kernels on TPU, the memory-sane jnp paths elsewhere (CPU smoke tests and the
+multi-pod dry-run; Pallas TPU kernels cannot lower on the CPU backend, and
+running them in interpret mode inside a 512-way SPMD program would be
+meaningless).
 
 ``impl`` values: "pallas" | "pallas_interpret" | "jnp" | "naive".
 """
 from __future__ import annotations
 
-import functools
-from typing import Optional
+import contextlib
+from typing import Iterator, Optional
 
 import jax
 import jax.numpy as jnp
@@ -22,9 +23,19 @@ from . import ref
 _DEFAULT_IMPL: str | None = None
 
 
-def set_default_impl(impl: str | None) -> None:
+@contextlib.contextmanager
+def default_impl(impl: str | None) -> Iterator[None]:
+    """Default ``impl`` for calls traced inside the block, restored on exit.
+
+    Read at trace time: wrap the first call of a jitted function (or its
+    ``lower``), not later calls, which reuse the traced program.
+    """
     global _DEFAULT_IMPL
-    _DEFAULT_IMPL = impl
+    prev, _DEFAULT_IMPL = _DEFAULT_IMPL, impl
+    try:
+        yield
+    finally:
+        _DEFAULT_IMPL = prev
 
 
 def _impl(impl: str | None) -> str:
@@ -47,10 +58,13 @@ def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
                                    softcap=softcap, kv_lens=kv_lens,
                                    q_offset=q_offset)
     if which in ("pallas", "pallas_interpret"):
+        if kv_lens is not None or not (isinstance(q_offset, int)
+                                       and q_offset == 0):
+            raise ValueError("the Pallas flash kernel is dense prefill; "
+                             "kv_lens / q_offset need impl='jnp'")
         from . import flash_attention as fa
         return fa.flash_attention(
             q, k, v, causal=causal, window=window, softcap=softcap,
-            kv_lens=kv_lens, q_offset=q_offset,
             interpret=(which == "pallas_interpret"))
     # jnp path: use the O(S) custom-VJP flash implementation whenever the
     # call is differentiable-shaped (dense packed batch, block-divisible);

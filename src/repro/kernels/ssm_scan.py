@@ -8,6 +8,10 @@ channel-parallel across the grid (d_inner is large: 16K for Jamba, so the
 grid supplies ample parallelism).  x/dt are streamed per channel block;
 B_t/C_t are shared across channel blocks (re-read per program — the
 recorded trade-off vs. broadcasting through VMEM).
+
+Step t reads and writes one row of each (T, ·) block at a dynamic sublane
+offset, which the TPU compiler accepts only for 32-bit data: the wrapper
+streams every sequence input in fp32 and casts y back afterwards.
 """
 from __future__ import annotations
 
@@ -53,6 +57,7 @@ def ssm_scan(x, dt, A, Bm, Cm, D, h0, *, d_block: int = 512,
     bd = min(d_block, Din)
     assert Din % bd == 0
     nd = Din // bd
+    f32 = lambda a: a.astype(jnp.float32)
     y, hT = pl.pallas_call(
         functools.partial(_kernel, T=T, bd=bd, N=N),
         grid=(B, nd),
@@ -70,10 +75,10 @@ def ssm_scan(x, dt, A, Bm, Cm, D, h0, *, d_block: int = 512,
             pl.BlockSpec((1, bd, N), lambda b, d: (b, d, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, T, Din), x.dtype),
+            jax.ShapeDtypeStruct((B, T, Din), jnp.float32),
             jax.ShapeDtypeStruct((B, Din, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
         interpret=interpret,
-    )(x, dt, A, Bm, Cm, D[None], h0)
-    return y, hT
+    )(f32(x), f32(dt), A, f32(Bm), f32(Cm), D[None], h0)
+    return y.astype(x.dtype), hT

@@ -1,7 +1,11 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# NOTE: the two lines above MUST run before any other import (including
-# `from repro...`): jax locks the device count at first initialization.
+# NOTE: the lines above MUST run before any other import (including
+# `from repro...`): jax locks the platform and device count at first
+# initialization.  This is a compile-only analysis on 512 virtual CPU
+# devices; naming the CPU platform keeps it off an attached TPU, which
+# belongs to one process at a time.
 #
 # CPU-faithfulness fix: XLA's CPU backend legalizes bf16 dots by inserting
 # f32 converts of the operands; while-loop-invariant code motion then hoists

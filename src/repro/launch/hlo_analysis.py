@@ -350,8 +350,6 @@ def summarize_cost(compiled) -> dict[str, Any]:
     out: dict[str, Any] = {}
     try:
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0]
         out["flops"] = float(ca.get("flops", 0.0))
         out["bytes_accessed"] = float(ca.get("bytes accessed", 0.0))
         out["transcendentals"] = float(ca.get("transcendentals", 0.0))

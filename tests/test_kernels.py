@@ -53,6 +53,19 @@ def test_flash_attention_window_softcap(window, softcap):
     assert float(jnp.max(jnp.abs(o - o_ref))) < 2e-5
 
 
+def test_flash_kernel_refuses_ragged_calls():
+    """The kernel is dense prefill; ragged or offset calls raise rather
+    than run on another path."""
+    from repro.kernels import ops
+    q = jnp.zeros((2, 8, 4, 16))
+    kv = jnp.zeros((2, 8, 2, 16))
+    with pytest.raises(ValueError, match="dense prefill"):
+        ops.flash_attention(q, kv, kv, kv_lens=jnp.array([8, 5]),
+                            impl="pallas_interpret")
+    with pytest.raises(ValueError, match="dense prefill"):
+        ops.flash_attention(q, kv, kv, q_offset=3, impl="pallas_interpret")
+
+
 def test_flash_vjp_matches_naive_autodiff():
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (2, 128, 4, 32), jnp.float32)
