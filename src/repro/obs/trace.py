@@ -24,7 +24,7 @@ from __future__ import annotations
 import contextlib
 import json
 import time
-from typing import Iterator, Optional
+from typing import Callable, ContextManager, Iterator, Optional
 
 __all__ = ["SpanTracer", "validate_chrome_trace", "TRACER",
            "WALL_PID", "SIM_PID", "wall_now"]
@@ -51,13 +51,20 @@ class SpanTracer:
 
     When ``enabled`` is False every record call is a boolean check and an
     early return, and ``span()`` yields without touching the clock.
+
+    ``annotate``, when given, is a factory of context managers (such as
+    ``jax.profiler.TraceAnnotation``) that an enabled ``span()`` also enters,
+    under ``"<track>.<name>"``: each span then lands in that profiler's own
+    trace too, on its clock.  This module imports nothing to make one.
     """
 
-    def __init__(self, enabled: bool = True, *, sample_every: int = 16):
+    def __init__(self, enabled: bool = True, *, sample_every: int = 16,
+                 annotate: Optional[Callable[[str], ContextManager]] = None):
         if sample_every < 1:
             raise ValueError("sample_every must be >= 1")
         self.enabled = enabled
         self.sample_every = sample_every
+        self.annotate = annotate
         self.events: list[dict] = []
         self._t0 = time.perf_counter()
         self._named_tracks: set[tuple[int, int]] = set()
@@ -86,14 +93,20 @@ class SpanTracer:
     # -- wall-clock spans ----------------------------------------------------
     @contextlib.contextmanager
     def span(self, name: str, *, track: str = "control",
-             **args) -> Iterator[None]:
-        """Time a wall-clock region (solver call, window handler)."""
+             **args) -> Iterator[dict]:
+        """Time a wall-clock region (solver call, window handler).  Yields
+        the span's args, to which the region may add what it learns before
+        it ends."""
         if not self.enabled:
-            yield
+            yield args
             return
         start = self._wall_us()
         try:
-            yield
+            if self.annotate is None:
+                yield args
+            else:
+                with self.annotate(f"{track}.{name}"):
+                    yield args
         finally:
             self.events.append({
                 "name": name, "ph": "X", "pid": WALL_PID,

@@ -1,4 +1,3 @@
 from .engine import EngineConfig, Request, ServingEngine
 from .cluster import ServingCluster
 from .kv_cache import BlockManager, OutOfBlocks
-from .metrics import LatencyStats

@@ -5,6 +5,23 @@ plane: slot-based batch, paged-block admission control (kv_cache.py),
 bucketed prefill compilation, greedy/temperature sampling, TPOT/TTFT
 metrics.  Chunked prefill is approximated at request granularity: at most
 ``prefill_budget_tokens`` of prompt work is admitted per engine step.
+
+Each step records its phases as wall-clock spans on the ``engine`` track of
+a ``repro.obs.trace.SpanTracer`` (the module's disabled ``TRACER`` unless one
+is given), each with arg ``step``, the step counter:
+
+* ``step``: the whole step.
+* ``admit``: admission; ``padded_tokens`` (the prefill programs' lengths),
+  ``rejected``, and ``stop`` (``no_slot`` | ``no_blocks`` | ``budget``) when
+  it left requests queued.  Inside it, per request (arg ``rid``):
+  ``prefill`` (``new_program`` when the padded length had no program yet),
+  ``insert`` and ``first_token``.
+* ``decode``: the batch's input tokens and the decode program's dispatch.
+* ``sample``: per-slot sampling and retirement; ``syncs``, the reads from
+  device to host made in it (``host_syncs`` counts every such read).
+
+The prefill and decode programs run under ``jax.named_scope`` of those
+names, so the device ops of a profiler trace carry the phase too.
 """
 from __future__ import annotations
 
@@ -19,6 +36,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import transformer as T
+from repro.obs import trace as obs_trace
 from repro.serving.kv_cache import BlockManager, OutOfBlocks
 
 
@@ -30,6 +48,7 @@ class Request:
     temperature: float = 0.0
     arrival_t: float = 0.0
     # filled during serving:
+    admit_t: float = -1.0            # taken from the queue for prefill
     generated: list[int] = dataclasses.field(default_factory=list)
     first_token_t: float = -1.0
     finish_t: float = -1.0
@@ -61,10 +80,12 @@ class EngineConfig:
 
 
 class ServingEngine:
-    def __init__(self, cfg: ModelConfig, params, ecfg: EngineConfig):
+    def __init__(self, cfg: ModelConfig, params, ecfg: EngineConfig,
+                 tracer: Optional[obs_trace.SpanTracer] = None):
         self.cfg = cfg
         self.params = params
         self.ecfg = ecfg
+        self.tracer = tracer if tracer is not None else obs_trace.TRACER
         self.cache, _ = T.init_cache(cfg, ecfg.max_batch, ecfg.max_seq)
         self.blocks = BlockManager(
             n_blocks=ecfg.max_batch * (ecfg.max_seq // ecfg.block_size),
@@ -74,12 +95,16 @@ class ServingEngine:
         self.queue: deque[Request] = deque()
         self.finished: list[Request] = []
         self.key = jax.random.PRNGKey(ecfg.seed)
+
         # append-mode decode (§Perf "cacheappend"): exact, and avoids the
         # full-cache rewrite per step — the serving default
-        self._decode = jax.jit(
-            lambda p, c, t, l: T.decode_step(cfg, p, c, t, l, append=True))
+        def decode(p, c, t, l):
+            with jax.named_scope("decode"):
+                return T.decode_step(cfg, p, c, t, l, append=True)
+        self._decode = jax.jit(decode)
         self._prefill_cache: dict[int, Callable] = {}
         self.steps = 0
+        self.host_syncs = 0              # reads from device to host
 
     # -- public -----------------------------------------------------------
     def submit(self, req: Request) -> None:
@@ -102,12 +127,22 @@ class ServingEngine:
     def _prefill_fn(self, padded_len: int):
         if padded_len not in self._prefill_cache:
             cfg = self.cfg
-            self._prefill_cache[padded_len] = jax.jit(
-                lambda p, toks: T.prefill(cfg, p, toks))
+
+            def prefill(p, toks):
+                with jax.named_scope("prefill"):
+                    return T.prefill(cfg, p, toks)
+            self._prefill_cache[padded_len] = jax.jit(prefill)
         return self._prefill_cache[padded_len]
 
-    def _admit(self) -> None:
+    def _admit(self, args: dict) -> None:
+        """Prefills queued requests into free slots, in order, while the
+        step's prompt budget lasts.  With the tracer on, notes in ``args``
+        (the ``admit`` span's) the padded tokens, the requests rejected and
+        why admission stopped with requests queued."""
+        span, step = self.tracer.span, self.steps
         budget = self.ecfg.prefill_budget_tokens
+        padded_tokens = rejected = 0
+        stop = None
         while self.queue and budget > 0:
             req = self.queue[0]
             L = len(req.prompt)
@@ -116,26 +151,37 @@ class ServingEngine:
                 # epoch stamp, same clock as arrival_t (see submit())
                 req.finish_t = time.time()  # lint: allow[sim-clock-purity]
                 self.finished.append(req)      # rejected: too long
+                rejected += 1
                 continue
             free_slots = [i for i, r in enumerate(self.slot_req) if r is None]
             if not free_slots:
-                return
+                stop = "no_slot"
+                break
             if not self.blocks.can_allocate(L + req.max_new_tokens):
-                return
+                stop = "no_blocks"
+                break
             if L > budget and self.n_active > 0:
-                return                          # defer big prefill (chunking)
+                stop = "budget"                 # defer big prefill (chunking)
+                break
             self.queue.popleft()
+            # epoch stamp, same clock as arrival_t (see submit())
+            req.admit_t = time.time()  # lint: allow[sim-clock-purity]
             slot = free_slots[0]
             self.blocks.allocate(req.rid, L)
             padded = max(8, 1 << (L - 1).bit_length())
             toks = np.zeros((1, padded), np.int32)
             toks[0, :L] = req.prompt
-            logits, pf_cache = self._prefill_fn(padded)(
-                self.params, jnp.asarray(toks))
-            self.cache = T.cache_insert(self.cfg, self.cache, pf_cache,
-                                        slot, L)
-            first = self._sample(logits[:, L - 1], req)
-            req.generated.append(int(first))
+            new = padded not in self._prefill_cache
+            with span("prefill", track="engine", step=step, rid=req.rid,
+                      new_program=new):
+                logits, pf_cache = self._prefill_fn(padded)(
+                    self.params, jnp.asarray(toks))
+            with span("insert", track="engine", step=step, rid=req.rid):
+                self.cache = T.cache_insert(self.cfg, self.cache, pf_cache,
+                                            slot, L)
+            with span("first_token", track="engine", step=step, rid=req.rid):
+                first = self._sample(logits[:, L - 1], req)
+            req.generated.append(first)
             # epoch stamp, same clock as arrival_t (see submit())
             req.first_token_t = time.time()  # lint: allow[sim-clock-purity]
             self.blocks.append_token(req.rid)
@@ -144,15 +190,22 @@ class ServingEngine:
             # lengths = number of tokens whose KV is in the cache
             self.lengths[slot] = L
             budget -= L
+            padded_tokens += padded
             if req.done:
                 self._retire(req)
+        if self.tracer.enabled:
+            args.update(padded_tokens=padded_tokens, rejected=rejected,
+                        stop=stop or ("budget" if self.queue else None))
 
     def _sample(self, logits, req: Request) -> int:
         if req.temperature <= 0:
-            return int(jnp.argmax(logits[-1] if logits.ndim > 1 else logits))
-        self.key, sub = jax.random.split(self.key)
-        lg = (logits[-1] if logits.ndim > 1 else logits) / req.temperature
-        return int(jax.random.categorical(sub, lg))
+            tok = jnp.argmax(logits[-1] if logits.ndim > 1 else logits)
+        else:
+            self.key, sub = jax.random.split(self.key)
+            lg = (logits[-1] if logits.ndim > 1 else logits) / req.temperature
+            tok = jax.random.categorical(sub, lg)
+        self.host_syncs += 1             # int() waits for the device
+        return int(tok)
 
     def _retire(self, req: Request) -> None:
         # epoch stamp, same clock as arrival_t (see submit())
@@ -166,27 +219,37 @@ class ServingEngine:
 
     def step(self) -> None:
         self.steps += 1
-        self._admit()
-        active = [r for r in self.slot_req if r is not None]
-        if not active:
-            return
-        toks = np.zeros(self.ecfg.max_batch, np.int32)
-        for r in active:
-            toks[r.slot] = r.generated[-1]
-        # decode writes the new token's KV at position `lengths`
-        logits, self.cache = self._decode(
-            self.params, self.cache, jnp.asarray(toks),
-            jnp.asarray(self.lengths))
+        span = self.tracer.span
+        with span("step", track="engine", step=self.steps):
+            with span("admit", track="engine", step=self.steps) as args:
+                self._admit(args)
+            active = [r for r in self.slot_req if r is not None]
+            if active:
+                self._decode_and_sample(active)
+
+    def _decode_and_sample(self, active: list[Request]) -> None:
+        span, step = self.tracer.span, self.steps
+        with span("decode", track="engine", step=step):
+            toks = np.zeros(self.ecfg.max_batch, np.int32)
+            for r in active:
+                toks[r.slot] = r.generated[-1]
+            # decode writes the new token's KV at position `lengths`
+            logits, self.cache = self._decode(
+                self.params, self.cache, jnp.asarray(toks),
+                jnp.asarray(self.lengths))
         # epoch stamp, same clock as arrival_t (see submit())
         now = time.time()  # lint: allow[sim-clock-purity]
-        for r in list(active):
-            tok = self._sample(logits[r.slot], r)
-            r.generated.append(tok)
-            self.lengths[r.slot] += 1
-            try:
-                self.blocks.append_token(r.rid)
-            except OutOfBlocks:
-                r.max_new_tokens = len(r.generated)
-            if r.done or self.lengths[r.slot] + 1 >= self.ecfg.max_seq:
-                r.finish_t = now
-                self._retire(r)
+        syncs = self.host_syncs
+        with span("sample", track="engine", step=step) as args:
+            for r in active:
+                tok = self._sample(logits[r.slot], r)
+                r.generated.append(tok)
+                self.lengths[r.slot] += 1
+                try:
+                    self.blocks.append_token(r.rid)
+                except OutOfBlocks:
+                    r.max_new_tokens = len(r.generated)
+                if r.done or self.lengths[r.slot] + 1 >= self.ecfg.max_seq:
+                    r.finish_t = now
+                    self._retire(r)
+            args["syncs"] = self.host_syncs - syncs

@@ -265,6 +265,44 @@ def test_tracer_sampling_and_disabled():
         SpanTracer(sample_every=0)
 
 
+def test_tracer_annotate_hook_entered_once_per_enabled_span():
+    entered, exited = [], []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+
+        def __exit__(self, *exc):
+            exited.append(self.name)
+            return False
+
+    tr = SpanTracer(enabled=True, annotate=Annotation)
+    with tr.span("step", track="engine", step=1) as args:
+        with tr.span("sample", track="engine", step=1) as inner:
+            inner["syncs"] = 3
+        args["occupancy"] = 3
+    with tr.span("resolve"):
+        pass
+    assert entered == ["engine.step", "engine.sample", "control.resolve"]
+    assert exited == ["engine.sample", "engine.step", "control.resolve"]
+    # tracer-only records are not annotations
+    tr.sim_span("w", 0, 1)
+    tr.wall_span("x", 0.0, 1.0)
+    assert len(entered) == 3
+    spans = {e["name"]: e for e in tr.events if e["ph"] == "X"}
+    assert spans["sample"]["args"] == {"step": 1, "syncs": 3}
+    assert spans["step"]["args"] == {"step": 1, "occupancy": 3}
+
+    off = SpanTracer(enabled=False, annotate=Annotation)
+    with off.span("step", track="engine") as args:
+        args["occupancy"] = 1
+    assert len(entered) == 3
+    assert [e for e in off.events if e["ph"] != "M"] == []
+
+
 def test_tracer_clear_keeps_metadata():
     tr = SpanTracer(enabled=True)
     tr.sim_span("w", 0, 1)
