@@ -16,12 +16,22 @@ is given), each with arg ``step``, the step counter:
   it left requests queued.  Inside it, per request (arg ``rid``):
   ``prefill`` (``new_program`` when the padded length had no program yet),
   ``insert`` and ``first_token``.
-* ``decode``: the batch's input tokens and the decode program's dispatch.
-* ``sample``: per-slot sampling and retirement; ``syncs``, the reads from
-  device to host made in it (``host_syncs`` counts every such read).
+* ``decode``: the batch's input tokens and temperatures, and the dispatch of
+  the decode program, which also samples every slot's next token on the
+  device.
+* ``sample``: the one read of the batch's tokens to the host, then per slot
+  the bookkeeping and retirement, which touch only host data; ``syncs``, the
+  reads from device to host made in it: 1 (``host_syncs`` counts every such
+  read, the first token of each admitted request included).
 
-The prefill and decode programs run under ``jax.named_scope`` of those
-names, so the device ops of a profiler trace carry the phase too.
+The decode program takes the argmax of every slot's logits; where some
+active slot has a temperature above 0 the host picks the program's other
+variant, which draws those slots' tokens from ``logits / temperature`` with
+a key folded from the engine's seed and the step counter.  The all-greedy
+variant passes no temperatures and draws nothing; both are compiled when
+the engine is built.  The prefill and decode programs run under
+``jax.named_scope`` of those names, so the device ops of a profiler trace
+carry the phase too.
 """
 from __future__ import annotations
 
@@ -94,14 +104,34 @@ class ServingEngine:
         self.slot_req: list[Optional[Request]] = [None] * ecfg.max_batch
         self.queue: deque[Request] = deque()
         self.finished: list[Request] = []
-        self.key = jax.random.PRNGKey(ecfg.seed)
+        # first tokens split ``key``; decode steps fold ``decode_key``
+        self.key, decode_key = jax.random.split(jax.random.PRNGKey(ecfg.seed))
 
         # append-mode decode (§Perf "cacheappend"): exact, and avoids the
         # full-cache rewrite per step — the serving default
-        def decode(p, c, t, l):
+        def decode(p, c, t, l, sampling):
+            """The batch's next tokens (int32[B]) and the new cache.
+            ``sampling`` is None (all greedy: the argmax) or (temperatures
+            float32[B], step): slots above 0 draw at their temperature."""
             with jax.named_scope("decode"):
-                return T.decode_step(cfg, p, c, t, l, append=True)
+                logits, c = T.decode_step(cfg, p, c, t, l, append=True)
+                tok = jnp.argmax(logits, -1)
+                if sampling is not None:
+                    temps, step = sampling
+                    hot = temps > 0
+                    lg = logits.astype(jnp.float32) / jnp.where(
+                        hot, temps, 1.0)[:, None]
+                    drawn = jax.random.categorical(
+                        jax.random.fold_in(decode_key, step), lg)
+                    tok = jnp.where(hot, drawn, tok)
+                return tok, c
         self._decode = jax.jit(decode)
+        # compile both variants now, so that neither the first step with a
+        # temperature nor the first all-greedy one compiles while serving
+        B, zeros = ecfg.max_batch, jnp.zeros(ecfg.max_batch, jnp.int32)
+        for sampling in (None, (np.zeros(B, np.float32), 0)):
+            jax.block_until_ready(
+                self._decode(params, self.cache, zeros, zeros, sampling))
         self._prefill_cache: dict[int, Callable] = {}
         self.steps = 0
         self.host_syncs = 0              # reads from device to host
@@ -180,7 +210,7 @@ class ServingEngine:
                 self.cache = T.cache_insert(self.cfg, self.cache, pf_cache,
                                             slot, L)
             with span("first_token", track="engine", step=step, rid=req.rid):
-                first = self._sample(logits[:, L - 1], req)
+                first = self._sample(logits[0, L - 1], req)
             req.generated.append(first)
             # epoch stamp, same clock as arrival_t (see submit())
             req.first_token_t = time.time()  # lint: allow[sim-clock-purity]
@@ -198,12 +228,12 @@ class ServingEngine:
                         stop=stop or ("budget" if self.queue else None))
 
     def _sample(self, logits, req: Request) -> int:
+        """A request's first token from the prompt's last logits (V,)."""
         if req.temperature <= 0:
-            tok = jnp.argmax(logits[-1] if logits.ndim > 1 else logits)
+            tok = jnp.argmax(logits)
         else:
             self.key, sub = jax.random.split(self.key)
-            lg = (logits[-1] if logits.ndim > 1 else logits) / req.temperature
-            tok = jax.random.categorical(sub, lg)
+            tok = jax.random.categorical(sub, logits / req.temperature)
         self.host_syncs += 1             # int() waits for the device
         return int(tok)
 
@@ -231,19 +261,24 @@ class ServingEngine:
         span, step = self.tracer.span, self.steps
         with span("decode", track="engine", step=step):
             toks = np.zeros(self.ecfg.max_batch, np.int32)
+            temps = np.zeros(self.ecfg.max_batch, np.float32)
             for r in active:
                 toks[r.slot] = r.generated[-1]
+                temps[r.slot] = r.temperature
+            # the greedy-only variant unless some slot samples
+            sampling = (temps, step) if (temps > 0).any() else None
             # decode writes the new token's KV at position `lengths`
-            logits, self.cache = self._decode(
+            next_toks, self.cache = self._decode(
                 self.params, self.cache, jnp.asarray(toks),
-                jnp.asarray(self.lengths))
-        # epoch stamp, same clock as arrival_t (see submit())
-        now = time.time()  # lint: allow[sim-clock-purity]
+                jnp.asarray(self.lengths), sampling)
         syncs = self.host_syncs
         with span("sample", track="engine", step=step) as args:
+            next_toks = jax.device_get(next_toks).tolist()
+            self.host_syncs += 1         # the step's one read to the host
+            # epoch stamp, same clock as arrival_t (see submit())
+            now = time.time()  # lint: allow[sim-clock-purity]
             for r in active:
-                tok = self._sample(logits[r.slot], r)
-                r.generated.append(tok)
+                r.generated.append(next_toks[r.slot])
                 self.lengths[r.slot] += 1
                 try:
                     self.blocks.append_token(r.rid)
