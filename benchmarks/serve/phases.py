@@ -51,6 +51,7 @@ import statistics  # noqa: E402
 import tempfile  # noqa: E402
 
 import run  # noqa: E402  (puts src/ and this directory on the path)
+import counts  # noqa: E402
 import runlib  # noqa: E402
 import trace_reduce  # noqa: E402
 from runlib import (admitting, decode_only, percentile,  # noqa: E402
@@ -307,8 +308,8 @@ def phases(name: str, bench: dict, config: dict, traffic: dict, *,
     tl = runlib.Timeline(start, w0, w0 + seconds, w0 + seconds + profile_s)
     trace_dir = tempfile.mkdtemp(prefix="serve_phases_")
     loop.serve(items, tl, traffic["drain_cap_s"], trace_dir, clock)
-    run_ = runlib.Run(m, ecfg.max_batch, peak, tl, loop.steps, loop.reqs,
-                      tl.w0 - t_start)
+    run_ = runlib.Run(counts.sizes(config), ecfg.max_batch, peak, tl,
+                      loop.steps, loop.reqs, tl.w0 - t_start)
     run_.trace = trace_reduce.load(trace_dir)
     spans, programs = read_profile(trace_dir)
     shutil.rmtree(trace_dir, ignore_errors=True)

@@ -1,6 +1,9 @@
 """Plain float32 reference of a dense pre-norm GQA decoder (Qwen2,
 InternLM2): RMSNorm, rotary embedding (rotate-half), causal grouped-query
 attention with optional q/k/v bias, SwiGLU MLP, tied or untied unembedding.
+Layers follow ``m["layers"]`` in order, each with its own weights; a
+``local`` layer's query at position i sees keys j with i - j <
+``sliding_window``.
 
 Written from the published architecture, with no kernel, cache or batching,
 and nothing imported from the program.  Every matrix product runs at
@@ -53,13 +56,19 @@ def hidden(m: dict, w: dict, toks, quant=None):
     G = H // KV
     S = toks.shape[0]
     x = w["embed"][toks].astype(f32)
-    causal = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
+    i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+    causal = i >= j
 
     def lin(spec, a, wt, a_axis, w_axis):
         return jnp.einsum(spec, qdq(a, a_axis, quant),
                           qdq(wt.astype(f32), w_axis, quant), precision=HI)
 
-    def layer(x, p):
+    def layer(x, p, spec):
+        kind, attn, mlp = spec
+        if kind != "attn" or attn not in ("global", "local") or mlp != "dense":
+            raise ValueError(f"dense_gqa has no {spec} layer")
+        mask = causal & (i - j < m["sliding_window"]) if attn == "local" \
+            else causal
         h = rms_norm(x, p["norm1"].astype(f32), eps)
         q = lin("sd,dhk->shk", h, p["wq"], -1, 0)
         k = lin("sd,dhk->shk", h, p["wk"], -1, 0)
@@ -70,17 +79,32 @@ def hidden(m: dict, w: dict, toks, quant=None):
         q, k = rope(q, theta), rope(k, theta)
         q = q.reshape(S, KV, G, Dh) / math.sqrt(Dh)
         s = jnp.einsum("skgd,tkd->kgst", q, k, precision=HI)
-        s = jnp.where(causal, s, NEG)
+        s = jnp.where(mask, s, NEG)
         pr = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("kgst,tkd->skgd", pr, v, precision=HI).reshape(S, H, Dh)
         x = x + lin("shk,hkd->sd", o, p["wo"], (1, 2), (0, 1))
         h = rms_norm(x, p["norm2"].astype(f32), eps)
         g = lin("sd,df->sf", h, p["w_gate"], -1, 0)
         u = lin("sd,df->sf", h, p["w_up"], -1, 0)
-        x = x + lin("sf,fd->sd", jax.nn.silu(g) * u, p["w_down"], -1, 0)
-        return x, None
+        return x + lin("sf,fd->sd", jax.nn.silu(g) * u, p["w_down"], -1, 0)
 
-    x, _ = jax.lax.scan(layer, x, w["layers"])
+    # each group: a scan over its repeats, every position of the period
+    # with its own weights and its own kind of layer
+    at = 0
+    for group in w["groups"]:
+        specs = m["layers"][at:at + len(group)]
+        reps = jax.tree.leaves(group)[0].shape[0]
+
+        def period(x, ps, specs=specs):
+            for p, spec in zip(ps, specs):
+                x = layer(x, p, spec)
+            return x, None
+
+        x, _ = jax.lax.scan(period, x, group)
+        at += len(group) * reps
+    if at != len(m["layers"]):
+        raise ValueError(f"weights of {at} layers, sizes of "
+                         f"{len(m['layers'])}")
     return rms_norm(x, w["final_norm"].astype(f32), eps)
 
 

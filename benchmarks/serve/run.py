@@ -42,6 +42,8 @@ ROOT = HERE.parents[1]
 sys.path.insert(0, str(ROOT / "src"))
 sys.path.insert(0, str(HERE))
 
+import counts  # noqa: E402
+
 TRACE_S = 5.0        # profiled seconds after the window (--trace 1)
 TAIL_S = 10.0        # arrivals scheduled past the window, for the drain
 
@@ -99,12 +101,15 @@ def set_compile_cache() -> None:
 
 
 def model_config(model: dict):
-    """The program's ModelConfig for the configuration file's sizes."""
+    """The program's ModelConfig for the configuration file's sizes, its
+    layer pattern stated as ``groups`` or as one ``period`` over
+    ``n_layers`` (``counts.groups``)."""
     from repro.configs.base import LayerSpec, ModelConfig
-    fields = {k: v for k, v in model.items() if k not in ("period", "n_layers")}
-    period = tuple(LayerSpec(**s) for s in model["period"])
-    return ModelConfig(groups=((period, model["n_layers"] // len(period)),),
-                       **fields)
+    fields = {k: v for k, v in model.items()
+              if k not in ("period", "n_layers", "groups")}
+    groups = tuple((tuple(LayerSpec(**s) for s in period), rep)
+                   for period, rep in counts.groups(model))
+    return ModelConfig(groups=groups, **fields)
 
 
 def log(msg: str) -> None:
@@ -112,7 +117,11 @@ def log(msg: str) -> None:
 
 
 def ref_sizes(model: dict) -> dict:
-    return {k: v for k, v in model.items() if isinstance(v, (int, float, str))}
+    """What the reference reads: the scalar sizes, and every layer in
+    order as ``(kind, attn_type, mlp)`` under ``"layers"`` (hashable, so
+    that ``check`` caches one program per configuration)."""
+    out = {k: v for k, v in model.items() if isinstance(v, (int, float, str))}
+    return dict(out, layers=counts.layers(model))
 
 
 def run_cell(name: str, bench: dict, config: dict, traffic: dict,
@@ -177,8 +186,8 @@ def run_cell(name: str, bench: dict, config: dict, traffic: dict,
     log(f"peak_bytes_in_use: {peak_bytes} (bytes_limit "
         f"{stats.get('bytes_limit', 'n/a')})")
 
-    run = runlib.Run(m, ecfg.max_batch, peak, tl, loop.steps, loop.reqs,
-                     tl.w0 - t_start)
+    run = runlib.Run(counts.sizes(config), ecfg.max_batch, peak, tl,
+                     loop.steps, loop.reqs, tl.w0 - t_start)
     result_device = {"platform": dev.platform, "kind": dev.device_kind,
                      "count": len(devices), "memory_peak_bytes": peak_bytes}
     breakdown = None

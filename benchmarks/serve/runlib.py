@@ -257,7 +257,7 @@ def admitting(steps) -> list[StepRec]:
 @dataclasses.dataclass
 class Run:
     """What a metric reader sees of one run."""
-    model: dict                  # the configuration file's "model" sizes
+    model: dict                  # counts.sizes of the configuration file
     max_batch: int
     peak: dict                   # peaks.json entry of this device
     tl: Timeline

@@ -31,6 +31,35 @@ def test_names_and_files():
                                           f"{m['name']}.py").is_file()
 
 
+@pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
+def test_config_states_its_cut(entry):
+    """Each key of ``model`` that the file's ``reduced`` lists has its
+    published value under ``published``, and a cut file says under
+    ``deployment`` how many chips share each layer and how."""
+    config = json.loads((ROOT / entry["file"]).read_text())
+    published = config.get("published", {})
+    for key in config["reduced"]:
+        assert key in config["model"], key
+        assert key in published and published[key] != config["model"][key]
+    if config["reduced"] or entry["reduced"]:
+        assert config.get("deployment", "").strip()
+
+
+def test_model_config_from_a_period_or_from_groups():
+    """One period over ``n_layers`` and the same layers as groups give the
+    ModelConfig that the program was given before groups could be
+    stated."""
+    import run
+    from repro.configs.base import LayerSpec, ModelConfig
+    m = json.loads((HERE / "configs" / "qwen2-1.5b.json").read_text())["model"]
+    fields = {k: v for k, v in m.items() if k not in ("period", "n_layers")}
+    before = ModelConfig(groups=(((LayerSpec(**m["period"][0]),), 28),),
+                         **fields)
+    assert run.model_config(m) == before
+    as_groups = dict(fields, groups=[{"period": m["period"], "repeat": 28}])
+    assert run.model_config(as_groups) == before
+
+
 def test_every_cell_reports_what_its_layer_metrics_move():
     import run
     cells = [w["name"] for w in BENCH["workloads"]]

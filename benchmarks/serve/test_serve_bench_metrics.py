@@ -14,7 +14,8 @@ import runlib  # noqa: E402
 from runlib import ReqRec, StepRec  # noqa: E402
 
 M = {"d_model": 4, "n_heads": 2, "n_kv_heads": 1, "head_dim": 2, "d_ff": 3,
-     "vocab_size": 5, "n_layers": 2, "qkv_bias": False, "tie_embeddings": True}
+     "vocab_size": 5, "n_layers": 2, "qkv_bias": False, "tie_embeddings": True,
+     "period": [{"kind": "attn", "attn_type": "global", "mlp": "dense"}]}
 PEAK = {"bf16_flops_per_s": 1e3, "hbm_bytes_per_s": 1e4}
 
 

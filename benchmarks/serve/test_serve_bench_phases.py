@@ -225,8 +225,8 @@ def test_reduced_cell_with_the_engines_spans(monkeypatch):
     for k in ("admit_wait_ms.p90", "admit_ms_per_ktok", "host_syncs.decode"):
         assert k in r and r[k] > 0
     assert "sample_idle_ms.decode" not in r
-    assert r["host_syncs.decode"] == pytest.approx(
-        out["window"]["occupancy.decode"])
+    # the argmax path reads the batch's tokens once a decode step
+    assert r["host_syncs.decode"] == 1
     assert r["admit_ms_per_ktok"] < r["prefill_ms_per_ktok"]
     assert r["admit_wait_ms.p90"] >= r["queue_wait_ms.p90"]
     assert all(v > 0 and math.isfinite(v) for v in out["tails"].values())

@@ -33,13 +33,34 @@ def _tiny_sampler(rng, n):
     return i, o
 
 
-def tiny_cell(**traffic_over):
-    """The cell's files with the model cut to a few widths and layers, the
-    engine to 4 slots and the traffic to short requests."""
+GLOBAL, LOCAL = ({"kind": "attn", "attn_type": t, "mlp": "dense"}
+                 for t in ("global", "local"))
+# a global layer, then (local, local, local, global) twice: prompts and
+# answers of up to 40 tokens cross the window of 16
+MIXED = {"groups": [{"period": [GLOBAL], "repeat": 1},
+                    {"period": [LOCAL, LOCAL, LOCAL, GLOBAL], "repeat": 2}],
+         "sliding_window": 16}
+
+
+def tiny_model(pattern=None) -> dict:
+    """The configuration's model at a few widths: two layers of its own
+    pattern, or the layer ``pattern`` given (a dict of model keys that
+    replaces ``period`` and ``n_layers``)."""
+    m = dict(run.cell_files(CELL)[2]["model"], d_model=64, n_heads=4,
+             n_kv_heads=2, head_dim=16, d_ff=128, vocab_size=256, n_layers=2)
+    if pattern is None:
+        return m
+    return dict({k: v for k, v in m.items() if k not in ("period", "n_layers")},
+                **pattern)
+
+
+def tiny_cell(pattern=None, **traffic_over):
+    """The cell's files with the model cut to a few widths and layers (see
+    ``tiny_model``), the engine to 4 slots and the traffic to short
+    requests."""
     bench, cell, config, traffic, checks = run.cell_files(CELL)
-    D, H, Dh, F = 64, 4, 16, 128
-    m = dict(config["model"], d_model=D, n_heads=H, n_kv_heads=2,
-             head_dim=Dh, d_ff=F, vocab_size=256, n_layers=2)
+    m = tiny_model(pattern)
+    D, H, Dh, F = m["d_model"], m["n_heads"], m["head_dim"], m["d_ff"]
     s_q, s_in = math.sqrt(2 / (D + H * Dh)), math.sqrt(2 / (D + F))
     # the embedding (tied head) widened so that the logits spread as widely
     # as at the configuration's own width, and gaps read on the same scale
@@ -57,8 +78,9 @@ def tiny_cell(**traffic_over):
     return bench, config, traffic, checks
 
 
-def run_tiny(seed=2**33 + 1, trace=False, impl="pallas_interpret", **over):
-    bench, config, traffic, checks = tiny_cell(**over)
+def run_tiny(seed=2**33 + 1, trace=False, impl="pallas_interpret",
+             pattern=None, **over):
+    bench, config, traffic, checks = tiny_cell(pattern, **over)
     with ops.default_impl(impl):
         return run.run_cell(CELL, bench, config, traffic, checks, seed=seed,
                             seconds=2.0, trace=trace, devices=jax.devices(),
@@ -142,6 +164,42 @@ def test_faults_come_out_not_correct(monkeypatch, fault):
     assert out["correct"] is False
     chk = out["check"]["logit_gap_max"]
     assert chk["value"] > chk["limit"]
+
+
+def _position_0_everywhere(orig):
+    """The reference given each group's first period position's weights at
+    every position (the single-stack view of the weights)."""
+    def plain(params):
+        w = orig(params)
+        return dict(w, groups=[tuple(g[0] for _ in g) for g in w["groups"]])
+    return plain
+
+
+def _no_window(orig):
+    def ref_sizes(model):
+        m = orig(model)
+        return dict(m, layers=tuple((k, "global", f)
+                                    for k, _, f in m["layers"]))
+    return ref_sizes
+
+
+@pytest.mark.parametrize("fault", [None, "position_0_weights", "no_window"])
+def test_reduced_mixed_cell(monkeypatch, fault):
+    """A model whose layers differ (two groups, windowed and full attention)
+    served through the harness reads ``correct`` true; a reference that
+    sees only each group's first position's weights, or no window, reads
+    it false."""
+    import weights
+    if fault == "position_0_weights":
+        monkeypatch.setattr(weights, "plain", _position_0_everywhere(
+            weights.plain))
+    elif fault == "no_window":
+        monkeypatch.setattr(run, "ref_sizes", _no_window(run.ref_sizes))
+    out = run_tiny(pattern=MIXED, impl="jnp" if fault else "pallas_interpret")
+    chk = out["check"]["logit_gap_max"]
+    assert out["correct"] is (fault is None), chk
+    assert (chk["value"] <= chk["limit"]) is (fault is None)
+    assert out["check"]["tokens_compared"]["value"] >= 32
 
 
 def test_entry_point_refuses_the_cpu():

@@ -42,11 +42,22 @@ def make_weights(abstract, init: dict, seed: int):
     return jax.block_until_ready(jax.jit(build)(weight_key(seed)))
 
 
-def plain(params) -> dict:
-    """The weights as the reference reads them: a flat dict with each
-    decoder layer's matrices stacked on a leading layer axis."""
-    layer = params["g0"][0]
-    flat = {"norm1": layer["norm1"], "norm2": layer["norm2"],
+def _flat(layer) -> dict:
+    return {"norm1": layer["norm1"], "norm2": layer["norm2"],
             **layer["mixer"], **layer["mlp"]}
-    return {"embed": params["embed"], "final_norm": params["final_norm"],
-            "lm_head": params.get("lm_head"), "layers": flat}
+
+
+def plain(params) -> dict:
+    """The weights as the reference reads them.  ``"groups"``: for each
+    group of the program's stack (``g0``, ``g1``, ...), one flat dict per
+    position of its period, each matrix stacked over the group's repeats on
+    a leading axis.  A model of one group with a period of one also has
+    that one dict as ``"layers"``."""
+    groups = []
+    while f"g{len(groups)}" in params:
+        groups.append(tuple(_flat(x) for x in params[f"g{len(groups)}"]))
+    out = {"embed": params["embed"], "final_norm": params["final_norm"],
+           "lm_head": params.get("lm_head"), "groups": groups}
+    if len(groups) == 1 and len(groups[0]) == 1:
+        out["layers"] = groups[0][0]
+    return out
