@@ -1,4 +1,4 @@
-"""The 10 assigned architectures, exact configs from the assignment table.
+"""The assigned architectures, exact configs from the assignment table.
 
 Sources noted per entry; every dimension below matches the assignment block.
 """
@@ -119,6 +119,8 @@ def jamba_1_5_large() -> ModelConfig:
     # [hybrid] 72L d_model=8192 64H (GQA kv=8) d_ff=24576 vocab=65536,
     # MoE 16e top-2 — Mamba+attn 1:7 interleave [arXiv:2403.19887].
     # Period of 8: attn at index 3, mamba elsewhere; MoE on odd layers.
+    # Its Mamba layers carry Jamba's dt/B/C norms (models/ssm.py); its
+    # attention keeps the default rope, which Jamba does not use.
     m_d = LayerSpec(kind="mamba", mlp="dense")
     m_e = LayerSpec(kind="mamba", mlp="moe")
     a_e = LayerSpec(kind="attn", attn_type="global", mlp="moe")
@@ -131,6 +133,26 @@ def jamba_1_5_large() -> ModelConfig:
         n_experts=16, moe_top_k=2, moe_d_ff=24576,
         mamba_d_state=16, mamba_expand=2, mamba_conv=4,
         optimizer="adafactor",
+    )
+
+
+def jamba2_3b() -> ModelConfig:
+    # [hybrid] 28L d_model=2560 20H (GQA kv=1) d_ff=8192 vocab=65536, tied
+    # [hf:ai21labs/AI21-Jamba2-3B config.json; arXiv:2403.19887].  Layer i
+    # is attention iff i % attn_layer_period (14) == attn_layer_offset (7),
+    # the HF Jamba order: 7 Mamba, attention, 6 Mamba, twice.  Mamba-1:
+    # d_state 16, d_conv 4, expand 2, dt_rank 160, conv bias, no in/out
+    # bias.  No MoE (num_experts 1); no positional encoding; head_dim
+    # 2560/20 = 128 (not given).
+    mamba = LayerSpec(kind="mamba", mlp="dense")
+    period = (mamba,) * 7 + (ATTN,) + (mamba,) * 6
+    return ModelConfig(
+        name="jamba2-3b", family="hybrid",
+        d_model=2560, n_heads=20, n_kv_heads=1, head_dim=128,
+        d_ff=8192, vocab_size=65536,
+        groups=((period, 2),),
+        rope_theta=None, tie_embeddings=True, norm_eps=1e-6,
+        mamba_d_state=16, mamba_expand=2, mamba_conv=4, mamba_dt_rank=160,
     )
 
 
@@ -157,5 +179,6 @@ ARCHS = {
     "gemma2-27b": gemma2_27b,
     "llama-3.2-vision-11b": llama32_vision_11b,
     "jamba-1.5-large-398b": jamba_1_5_large,
+    "jamba2-3b": jamba2_3b,
     "rwkv6-1.6b": rwkv6_1_6b,
 }

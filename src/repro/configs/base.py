@@ -37,7 +37,7 @@ class ModelConfig:
 
     # attention options
     qkv_bias: bool = False
-    rope_theta: float = 10_000.0
+    rope_theta: Optional[float] = 10_000.0   # None: no positional encoding
     attn_softcap: Optional[float] = None
     final_softcap: Optional[float] = None
     sliding_window: Optional[int] = None

@@ -30,8 +30,12 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding. x: (B, S, H, Dh); positions: (B, S)."""
+def rope(x: jax.Array, positions: jax.Array,
+         theta: Optional[float]) -> jax.Array:
+    """Rotary embedding. x: (B, S, H, Dh); positions: (B, S).  ``theta``
+    None: the model has no positional encoding (Jamba), x is returned."""
+    if theta is None:
+        return x
     dh = x.shape[-1]
     half = dh // 2
     freqs = jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)

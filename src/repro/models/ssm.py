@@ -3,6 +3,13 @@
 Both expose:  init_* -> (params, axes);  *_forward (full sequence, returns
 final recurrent state for prefill→decode handoff);  *_decode (single token);
 init_*_state -> (state, axes).
+
+The Mamba mixer is Jamba's (arXiv:2403.19887; HF ``JambaMambaMixer``): after
+``x_proj`` it RMS-normalises dt (``dt_rank``), B and C (``d_state``) each
+with its own scale.  ``mamba_forward`` takes each row's true length, so a
+right-padded prefill hands decode the state at the prompt's end: the scan
+gets dt = 0 past it (h stays as it is) and the conv state is the K-1 inputs
+before it.  RWKV's prefill has no lengths yet: its state absorbs padding.
 """
 from __future__ import annotations
 
@@ -15,6 +22,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import constrain
 from repro.kernels import ops
+from repro.models.layers import rms_norm
 
 Params = dict
 Axes = dict
@@ -39,6 +47,10 @@ def init_mamba(cfg: ModelConfig, key) -> tuple[Params, Axes]:
             jnp.arange(1, N + 1, dtype=jnp.float32), (Din, N))).astype(jnp.float32),
         "Dskip": jnp.ones((Din,), jnp.float32),
         "out_proj": (jax.random.normal(ks[4], (Din, D)) * (Din ** -0.5)).astype(pd),
+        # Jamba's inner norms of dt, B and C
+        "dt_norm": jnp.ones((R,), jnp.float32),
+        "B_norm": jnp.ones((N,), jnp.float32),
+        "C_norm": jnp.ones((N,), jnp.float32),
     }
     a: Axes = {
         "in_proj": ("model_d", "d_inner"),
@@ -50,15 +62,21 @@ def init_mamba(cfg: ModelConfig, key) -> tuple[Params, Axes]:
         "A_log": ("d_inner", "state"),
         "Dskip": ("d_inner",),
         "out_proj": ("d_inner", "model_d"),
+        "dt_norm": (None,),
+        "B_norm": ("state",),
+        "C_norm": ("state",),
     }
     return p, a
 
 
-def _mamba_conv(p: Params, x_in: jax.Array, conv_state: jax.Array):
+def _mamba_conv(p: Params, x_in: jax.Array, conv_state: jax.Array,
+                lengths=None):
     """Causal depthwise conv, kernel K (small, unrolled).
 
-    x_in: (B, S, Din); conv_state: (B, K-1, Din) trailing context.
-    Returns (conv_out (B,S,Din), new_state (B,K-1,Din)).
+    x_in: (B, S, Din); conv_state: (B, K-1, Din) trailing context; lengths:
+    (B,) int32 true lengths of right-padded rows, or None (all S).
+    Returns (conv_out (B,S,Din), new_state (B,K-1,Din)): the K-1 inputs
+    before each row's length, the old state filling what the row lacks.
     """
     K = p["conv_w"].shape[0]
     dt = x_in.dtype
@@ -68,24 +86,41 @@ def _mamba_conv(p: Params, x_in: jax.Array, conv_state: jax.Array):
     w = p["conv_w"].astype(dt)
     out = sum(w[i][None, None] * jax.lax.dynamic_slice_in_dim(padded, i, S, 1)
               for i in range(K)) + out
-    new_state = padded[:, S:]
+    if lengths is None:
+        new_state = padded[:, S:]
+    else:
+        # inputs lengths-K+1 .. lengths-1 sit at padded[lengths .. +K-2]
+        idx = lengths[:, None] + jnp.arange(K - 1)[None, :]
+        new_state = jnp.take_along_axis(padded, idx[..., None], axis=1)
     return out, new_state
 
 
-def mamba_forward(cfg: ModelConfig, p: Params, x: jax.Array, state: dict):
+def mamba_forward(cfg: ModelConfig, p: Params, x: jax.Array, state: dict,
+                  lengths=None):
+    """Jamba's Mamba mixer over x (B, S, D) from ``state``.  ``lengths``
+    (B,) int32: each row's true length in a right-padded batch; the state
+    returned is the state at that length (None: all S positions)."""
     B, S, D = x.shape
     dt_ = x.dtype
     Din, N, R = cfg.d_inner, cfg.mamba_d_state, cfg.dt_rank
+    eps = cfg.norm_eps
     xz = jnp.einsum("bsd,de->bse", x, p["in_proj"].astype(dt_))
     x_in, z = jnp.split(xz, 2, axis=-1)
     x_in = constrain(x_in, ("batch", "seq", "d_inner"))
-    conv_out, conv_new = _mamba_conv(p, x_in, state["conv"])
+    conv_out, conv_new = _mamba_conv(p, x_in, state["conv"], lengths)
     xc = jax.nn.silu(conv_out)
     proj = jnp.einsum("bse,ef->bsf", xc, p["x_proj"].astype(dt_))
     dt_low, Bm, Cm = jnp.split(proj, [R, R + N], axis=-1)
+    dt_low = rms_norm(dt_low, p["dt_norm"], eps)
+    Bm = rms_norm(Bm, p["B_norm"], eps)
+    Cm = rms_norm(Cm, p["C_norm"], eps)
     dt = jax.nn.softplus(
         jnp.einsum("bsr,re->bse", dt_low.astype(jnp.float32), p["dt_w"].astype(jnp.float32))
         + p["dt_bias"])
+    if lengths is not None:
+        # dt = 0 past the length: exp(0·A) = 1 and dt·x·B = 0, so h holds
+        dt = jnp.where(jnp.arange(S)[None, :, None] < lengths[:, None, None],
+                       dt, 0.0)
     A = -jnp.exp(p["A_log"])
     y, h_fin = ops.ssm_scan(xc, dt, A, Bm, Cm, p["Dskip"], state["h"])
     y = y * jax.nn.silu(z)

@@ -174,7 +174,11 @@ def _apply_layer(cfg: ModelConfig, spec: LayerSpec, p, x, state, *, mode,
     elif spec.kind == "mamba":
         st = state["mixer"] if state is not None else SSM.init_mamba_state(
             cfg, x.shape[0])[0]
-        mix_out, new_mix_state = SSM.mamba_forward(cfg, p["mixer"], h_in, st)
+        # in decode ``lengths`` counts cached tokens; in prefill, the rows'
+        # true lengths, past which the state must not move
+        mix_out, new_mix_state = SSM.mamba_forward(
+            cfg, p["mixer"], h_in, st,
+            lengths=lengths if mode == "prefill" else None)
     elif spec.kind == "rwkv":
         st = state["mixer"] if state is not None else SSM.init_rwkv_state(
             cfg, x.shape[0])[0]
@@ -358,8 +362,10 @@ def _unembed(cfg: ModelConfig, params, h):
 
 
 def forward(cfg: ModelConfig, params, tokens, *, vision_embeds=None,
-            mode: str = "train"):
-    """Full-sequence pass. Returns (logits, cache|None, aux)."""
+            mode: str = "train", lengths=None):
+    """Full-sequence pass. Returns (logits, cache|None, aux).  ``lengths``
+    (B,) int32, prefill only: each row's true length in a right-padded
+    batch (see ``prefill``)."""
     h = _embed(cfg, params, tokens)
     B, S = h.shape[:2]
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
@@ -374,7 +380,7 @@ def forward(cfg: ModelConfig, params, tokens, *, vision_embeds=None,
     for gi, (period, rep) in enumerate(cfg.groups):
         h, states, aux = _run_group(
             cfg, period, params[f"g{gi}"], h, mode=mode,
-            positions=positions, lengths=None, vision_kv=vision_kv)
+            positions=positions, lengths=lengths, vision_kv=vision_kv)
         if states is not None:
             caches[f"g{gi}"] = states
         aux_tot = {k: aux_tot[k] + aux[k] for k in aux_tot}
@@ -383,10 +389,17 @@ def forward(cfg: ModelConfig, params, tokens, *, vision_embeds=None,
     return logits, (caches if mode == "prefill" else None), aux_tot
 
 
-def prefill(cfg: ModelConfig, params, tokens, *, vision_embeds=None):
-    """Returns (logits, cache). Cache seq capacity == prompt length."""
-    logits, cache, _ = forward(cfg, params, tokens,
-                               vision_embeds=vision_embeds, mode="prefill")
+def prefill(cfg: ModelConfig, params, tokens, lengths=None, *,
+            vision_embeds=None):
+    """Returns (logits, cache). Cache seq capacity == prompt length.
+
+    ``lengths`` (B,) int32: each row's true length when ``tokens`` is
+    right-padded.  Recurrent layers then return their state at that length;
+    attention ignores it, since a causal query below the length never sees
+    the padding.  A traced argument: one program serves every length of a
+    padded shape.  None: every row is all of ``tokens``."""
+    logits, cache, _ = forward(cfg, params, tokens, vision_embeds=vision_embeds,
+                               mode="prefill", lengths=lengths)
     return logits, cache
 
 

@@ -14,8 +14,10 @@ is given), each with arg ``step``, the step counter:
 * ``admit``: admission; ``padded_tokens`` (the prefill programs' lengths),
   ``rejected``, and ``stop`` (``no_slot`` | ``no_blocks`` | ``budget``) when
   it left requests queued.  Inside it, per request (arg ``rid``):
-  ``prefill`` (``new_program`` when the padded length had no program yet),
-  ``insert`` and ``first_token``.
+  ``prefill`` (``new_program`` when the padded length had no program yet;
+  ``pad_tokens``, the padded length less the prompt's), ``insert``
+  (``state_bytes``, the recurrent and conv state written whole into the
+  slot; ``kv_bytes``, the prompt's K and V) and ``first_token``.
 * ``decode``: the batch's input tokens and temperatures, and the dispatch of
   the decode program, which also samples every slot's next token on the
   device.
@@ -133,6 +135,8 @@ class ServingEngine:
             jax.block_until_ready(
                 self._decode(params, self.cache, zeros, zeros, sampling))
         self._prefill_cache: dict[int, Callable] = {}
+        self._state_bytes, self._kv_bytes = _slot_bytes(cfg, self.cache,
+                                                        ecfg.max_batch)
         self.steps = 0
         self.host_syncs = 0              # reads from device to host
 
@@ -158,9 +162,9 @@ class ServingEngine:
         if padded_len not in self._prefill_cache:
             cfg = self.cfg
 
-            def prefill(p, toks):
+            def prefill(p, toks, lengths):
                 with jax.named_scope("prefill"):
-                    return T.prefill(cfg, p, toks)
+                    return T.prefill(cfg, p, toks, lengths)
             self._prefill_cache[padded_len] = jax.jit(prefill)
         return self._prefill_cache[padded_len]
 
@@ -203,10 +207,13 @@ class ServingEngine:
             toks[0, :L] = req.prompt
             new = padded not in self._prefill_cache
             with span("prefill", track="engine", step=step, rid=req.rid,
-                      new_program=new):
+                      new_program=new, pad_tokens=padded - L):
+                # the true length is an argument: one program per bucket
                 logits, pf_cache = self._prefill_fn(padded)(
-                    self.params, jnp.asarray(toks))
-            with span("insert", track="engine", step=step, rid=req.rid):
+                    self.params, jnp.asarray(toks), np.array([L], np.int32))
+            with span("insert", track="engine", step=step, rid=req.rid,
+                      state_bytes=self._state_bytes,
+                      kv_bytes=L * self._kv_bytes):
                 self.cache = T.cache_insert(self.cfg, self.cache, pf_cache,
                                             slot, L)
             with span("first_token", track="engine", step=step, rid=req.rid):
@@ -288,3 +295,17 @@ class ServingEngine:
                     r.finish_t = now
                     self._retire(r)
             args["syncs"] = self.host_syncs - syncs
+
+
+def _slot_bytes(cfg: ModelConfig, cache, batch: int) -> tuple[int, int]:
+    """What ``cache_insert`` writes into one slot: (bytes of the state that
+    it writes whole, bytes of one position's self-attention K and V)."""
+    state = kv = 0
+    for gi, (period, _) in enumerate(cfg.groups):
+        for spec, layer in zip(period, cache[f"g{gi}"]):
+            n = sum(x.nbytes for x in layer["mixer"].values()) // batch
+            if spec.kind == "attn" and spec.attn_type != "cross":
+                kv += n // layer["mixer"]["k"].shape[2]
+            else:
+                state += n
+    return state, kv

@@ -198,6 +198,29 @@ def test_ssm_kernel(B, T, Din, N, bd):
     assert float(jnp.max(jnp.abs(hT - h_ref))) < 1e-4
 
 
+def test_ssm_kernel_carries_the_state_across_time_blocks():
+    """Four time blocks of 16, from a nonzero state, with dt = 0 past
+    position 37: y matches the sequential scan, and the final state is the
+    sequential scan's state after position 37 (dt = 0 leaves h as it is)."""
+    ks = jax.random.split(KEY, 7)
+    B, T, Din, N, L = 2, 64, 128, 16, 37
+    x = jax.random.normal(ks[0], (B, T, Din))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, T, Din))) * 0.1
+    dt = jnp.where(jnp.arange(T)[None, :, None] < L, dt, 0.0)
+    A = -jnp.exp(jax.random.normal(ks[2], (Din, N)) * 0.3)
+    Bm = jax.random.normal(ks[3], (B, T, N))
+    Cm = jax.random.normal(ks[4], (B, T, N))
+    D = jax.random.normal(ks[5], (Din,))
+    h0 = jax.random.normal(ks[6], (B, Din, N))
+    y_ref, _ = ref.ssm_sequential(x, dt, A, Bm, Cm, D, h0)
+    _, h_L = ref.ssm_sequential(x[:, :L], dt[:, :L], A, Bm[:, :L], Cm[:, :L],
+                                D, h0)
+    y, hT = ssm_scan(x, dt, A, Bm, Cm, D, h0, d_block=64, t_block=16,
+                     interpret=True)
+    assert float(jnp.max(jnp.abs(y - y_ref))) < 1e-4
+    assert float(jnp.max(jnp.abs(hT - h_L))) < 1e-4
+
+
 def test_ssm_chunked_matches_sequential():
     ks = jax.random.split(KEY, 6)
     B, T, Din, N = 2, 50, 32, 8      # pad path

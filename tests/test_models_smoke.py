@@ -164,6 +164,7 @@ def test_param_counts_plausible():
         "gemma2-27b": (24e9, 30e9),
         "kimi-k2-1t-a32b": (0.85e12, 1.25e12),
         "jamba-1.5-large-398b": (3.2e11, 4.7e11),
+        "jamba2-3b": (2.9e9, 3.2e9),
         "rwkv6-1.6b": (1.2e9, 2.2e9),
         "granite-moe-1b-a400m": (0.9e9, 1.7e9),
         "llama-3.2-vision-11b": (8e9, 12e9),
@@ -185,6 +186,7 @@ def test_long_context_applicability():
     skip = {a: applicable(get_config(a), SHAPES["long_500k"])[0]
             for a in ARCHS}
     assert skip["rwkv6-1.6b"] and skip["jamba-1.5-large-398b"]
+    assert skip["jamba2-3b"]
     assert skip["gemma2-27b"]                      # sliding-window local
     assert not skip["qwen2-1.5b"] and not skip["kimi-k2-1t-a32b"]
-    assert sum(skip.values()) == 3
+    assert sum(skip.values()) == 4

@@ -131,6 +131,37 @@ def test_ssm_scan_jamba_widths(one_chip):
         _sds(one_chip, (B, Din, N), f32)))
 
 
+def test_jamba2_prefill_step_full_width(one_chip):
+    """The served prefill of ``jamba2-3b`` at its longest bucket: the Pallas
+    scan at d_inner 5120 over 2048 positions in 26 layers, the flash kernel
+    at 20 query heads over 1 KV head, the true length an argument."""
+    cfg = get_config("jamba2-3b")
+    params = _on(one_chip, T.abstract_params(cfg))
+    toks = _sds(one_chip, (1, 2048), jnp.int32)
+    lens = _sds(one_chip, (1,), jnp.int32)
+    with ops.default_impl("pallas"):
+        compiled = _compile(lambda p, t, n: T.prefill(cfg, p, t, n),
+                            params, toks, lens)
+    _assert_kernel(compiled)
+    assert summarize_cost(compiled)["memory"]["peak_bytes_per_device"] \
+        < V5E_HBM_BYTES
+
+
+def test_jamba2_append_decode_step_full_width(one_chip):
+    """The served decode of ``jamba2-3b``: 128 slots of recurrent state and
+    4096 positions of KV, append mode."""
+    cfg = get_config("jamba2-3b")
+    params = _on(one_chip, T.abstract_params(cfg))
+    cache = _on(one_chip, T.abstract_cache(cfg, 128, 4096))
+    toks = _sds(one_chip, (128,), jnp.int32)
+    lens = _sds(one_chip, (128,), jnp.int32)
+    compiled = _compile(
+        lambda p, c, t, l: T.decode_step(cfg, p, c, t, l, append=True),
+        params, cache, toks, lens)
+    assert summarize_cost(compiled)["memory"]["peak_bytes_per_device"] \
+        < V5E_HBM_BYTES
+
+
 def test_moe_gating_granite_widths(one_chip):
     from repro.kernels.moe_gating import moe_gating_topk
     cfg = get_config("granite-moe-1b-a400m")
