@@ -1,21 +1,27 @@
 """Pallas TPU Mamba selective scan.
 
-Grid (B, n_channel_blocks, n_time_blocks): each program owns a (bd, N) state
-slab in VMEM fp32, carried across the time blocks (the innermost,
-sequential axis), and walks its tb positions with a fori loop:
-    h <- exp(dt_t·A)⊙h + (dt_t·x_t)·B_t ;  y_t = h·C_t + D⊙x_t
-Per-step work is elementwise over (bd, N) plus an N-reduction — VPU-shaped,
-channel-parallel across the grid (d_inner is large: 16K for Jamba, so the
-grid supplies ample parallelism).  x/dt are streamed per channel block;
-B_t/C_t are shared across channel blocks (re-read per program — the
-recorded trade-off vs. broadcasting through VMEM).  Blocking time keeps the
-VMEM working set at (tb, bd) whatever the length: the x, dt and y blocks of
-a whole 2048-position prompt at bd 512, double-buffered, need 24 MiB of the
-16 MiB a kernel may use.
+Grid (B, n_channel_blocks, n_time_blocks): each program owns an (N, bd)
+state slab in VMEM fp32, carried across the time blocks (the innermost,
+sequential axis):
+    h <- exp(dt_t·Aᵀ)⊙h + B_tᵀ·(dt_t·x_t) ;  y_t = C_t·h + D⊙x_t
+The state is laid out (d_state, channels): channels fill the 128 lanes and
+the N states the sublanes, so at N = 16 a 512-channel slab is 8 full
+vector registers, and a position's x_t, dt_t and y_t are (1, bd) rows that
+broadcast over sublanes with no relayout; y_t is a sublane reduction.
 
-Step t reads and writes one row of each (tb, ·) block at a dynamic sublane
-offset, which the TPU compiler accepts only for 32-bit data: the wrapper
-streams every sequence input in fp32 and casts y back afterwards.
+A loop turn takes ``ROWS`` positions: x, dt and y move as aligned
+(ROWS, bd) tiles, and B and C as a (ROWS, N) tile transposed once into
+(N, ROWS), whose static columns broadcast over lanes.  Inside a turn only
+h <- a⊙h + b is carried from one position to the next, so the exp, b and y
+of neighbouring positions overlap.
+
+Blocking time keeps the VMEM working set at (tb, bd) whatever the length,
+and the wrapper takes the widest channel block whose double-buffered blocks
+fit ``_VMEM_BYTES``: wider blocks give a position more independent vector
+registers of work.  The wrapper streams every sequence input in fp32 and
+casts y back afterwards.  Outside the kernel the state stays (B, Din, N),
+as A stays (Din, N): the kernel transposes h0, hT and its A block once per
+program.
 """
 from __future__ import annotations
 
@@ -26,57 +32,79 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+ROWS = 8                # positions a loop turn: one f32 sublane tile
+_LANES = 128
+_VMEM_BYTES = 12 * 2**20    # of the 16 MiB of VMEM a kernel may use on v5e
+
 
 def _kernel(x_ref, dt_ref, A_ref, B_ref, C_ref, D_ref, h0_ref, y_ref, hT_ref,
-            h, *, tb, bd, N):
+            h, *, tb):
     ti = pl.program_id(2)
 
     @pl.when(ti == 0)
     def _init():
-        h[...] = h0_ref[0].astype(jnp.float32)               # (bd, N)
+        h[...] = h0_ref[0].T                                 # (N, bd)
 
-    A = A_ref[...].astype(jnp.float32)                       # (bd, N)
-    D = D_ref[...].astype(jnp.float32)                       # (1, bd)
+    At = A_ref[...].T                                        # (N, bd)
+    D = D_ref[...]                                           # (1, bd)
 
-    def step(t, _):
-        x_t = x_ref[0, t, :].astype(jnp.float32)             # (bd,)
-        dt_t = dt_ref[0, t, :].astype(jnp.float32)
-        B_t = B_ref[0, t, :].astype(jnp.float32)             # (N,)
-        C_t = C_ref[0, t, :].astype(jnp.float32)
-        a = jnp.exp(dt_t[:, None] * A)
-        b = (dt_t * x_t)[:, None] * B_t[None, :]
-        h_new = a * h[...] + b
-        h[...] = h_new
-        y = jnp.einsum("dn,n->d", h_new, C_t,
-                       preferred_element_type=jnp.float32) + D[0] * x_t
-        y_ref[0, t, :] = y.astype(y_ref.dtype)
-        return 0
+    def turn(g, h_val):
+        t0 = pl.multiple_of(g * ROWS, ROWS)
+        x = x_ref[0, pl.ds(t0, ROWS), :]                     # (ROWS, bd)
+        dt = dt_ref[0, pl.ds(t0, ROWS), :]
+        Bt = B_ref[0, pl.ds(t0, ROWS), :].T                  # (N, ROWS)
+        Ct = C_ref[0, pl.ds(t0, ROWS), :].T
+        u = dt * x
+        ys = []
+        for j in range(ROWS):
+            a = jnp.exp(dt[j:j + 1] * At)                    # (N, bd)
+            h_val = a * h_val + Bt[:, j:j + 1] * u[j:j + 1]
+            ys.append(jnp.sum(Ct[:, j:j + 1] * h_val, axis=0, keepdims=True))
+        y_ref[0, pl.ds(t0, ROWS), :] = jnp.concatenate(ys, axis=0) + D * x
+        return h_val
 
-    jax.lax.fori_loop(0, tb, step, 0)
+    h[...] = jax.lax.fori_loop(0, tb // ROWS, turn, h[...])
 
     @pl.when(ti == pl.num_programs(2) - 1)
     def _done():
-        hT_ref[0] = h[...]
+        hT_ref[0] = h[...].T                                 # (bd, N)
+
+
+def _channel_block(Din: int, N: int, tb: int) -> int:
+    """The widest multiple of 128 lanes that divides Din and keeps the
+    double-buffered fp32 blocks within ``_VMEM_BYTES``: x, dt and y of tb
+    positions, and A, h0 and hT, whose N columns fill whole 128-lane tiles;
+    all of Din where it is no multiple of 128."""
+    if Din % _LANES:
+        return Din
+    per_channel = 2 * 4 * (3 * tb + 3 * -(-N // _LANES) * _LANES)
+    return max((bd for bd in range(_LANES, Din + 1, _LANES)
+                if Din % bd == 0 and bd * per_channel <= _VMEM_BYTES),
+               default=_LANES)
 
 
 @functools.partial(jax.jit, static_argnames=("d_block", "t_block",
                                              "interpret"))
-def ssm_scan(x, dt, A, Bm, Cm, D, h0, *, d_block: int = 512,
+def ssm_scan(x, dt, A, Bm, Cm, D, h0, *, d_block: int | None = None,
              t_block: int = 256, interpret: bool = False):
     """x/dt: (B,T,Din); A: (Din,N); Bm/Cm: (B,T,N); D: (Din,);
-    h0: (B,Din,N).  Time blocks of ``t_block`` positions where they divide
-    T, else one block of T."""
+    h0: (B,Din,N).  Returns y (B,T,Din) in x's dtype and hT (B,Din,N) fp32.
+    T is padded with dt = 0, which leaves h as it is, to whole time blocks
+    of ``t_block`` positions (a multiple of ROWS), or to one block of T
+    rounded up to ROWS where that is shorter.  ``d_block`` (tests): the
+    channel block, else the wrapper's choice."""
     B, T, Din = x.shape
     N = A.shape[-1]
-    bd = min(d_block, Din)
+    assert t_block % ROWS == 0
+    tb = min(t_block, -(-T // ROWS) * ROWS)
+    Tp = -(-T // tb) * tb
+    bd = d_block or _channel_block(Din, N, tb)
     assert Din % bd == 0
-    nd = Din // bd
-    tb = t_block if T % t_block == 0 else T
-    nt = T // tb
     f32 = lambda a: a.astype(jnp.float32)
+    pad = lambda a: jnp.pad(f32(a), ((0, 0), (0, Tp - T), (0, 0)))
     y, hT = pl.pallas_call(
-        functools.partial(_kernel, tb=tb, bd=bd, N=N),
-        grid=(B, nd, nt),
+        functools.partial(_kernel, tb=tb),
+        grid=(B, Din // bd, Tp // tb),
         in_specs=[
             pl.BlockSpec((1, tb, bd), lambda b, d, t: (b, t, d)),
             pl.BlockSpec((1, tb, bd), lambda b, d, t: (b, t, d)),
@@ -91,12 +119,12 @@ def ssm_scan(x, dt, A, Bm, Cm, D, h0, *, d_block: int = 512,
             pl.BlockSpec((1, bd, N), lambda b, d, t: (b, d, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, T, Din), jnp.float32),
+            jax.ShapeDtypeStruct((B, Tp, Din), jnp.float32),
             jax.ShapeDtypeStruct((B, Din, N), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((N, bd), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(f32(x), f32(dt), A, f32(Bm), f32(Cm), D[None], h0)
-    return y.astype(x.dtype), hT
+    )(pad(x), pad(dt), f32(A), pad(Bm), pad(Cm), f32(D)[None], f32(h0))
+    return y[:, :T].astype(x.dtype), hT

@@ -181,20 +181,36 @@ def test_rwkv6_chunked_matches_sequential():
 # ---------------------------------------------------------------------------
 # Mamba selective scan
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("B,T,Din,N,bd", [(2, 32, 64, 8, 32),
-                                          (1, 64, 128, 16, 128)])
-def test_ssm_kernel(B, T, Din, N, bd):
-    ks = jax.random.split(KEY, 6)
-    x = jax.random.normal(ks[0], (B, T, Din))
+@pytest.mark.parametrize("B,T,Din,N,bd,dtype,h0_scale", [
+    (2, 32, 64, 8, 32, jnp.float32, 0.0),
+    (1, 64, 128, 16, 128, jnp.float32, 0.0),
+    (1, 8, 256, 16, 128, jnp.float32, 0.0),       # the smallest bucket
+    (1, 128, 256, 16, 128, jnp.float32, 0.0),
+    (1, 384, 256, 16, 128, jnp.float32, 0.0),     # T no multiple of t_block
+    (2, 20, 128, 16, 128, jnp.float32, 0.0),      # T no multiple of 8
+    (1, 64, 640, 16, None, jnp.float32, 0.0),     # Jamba2's N; bd chosen
+    (1, 128, 5120, 16, None, jnp.float32, 0.0),   # Jamba2's d_inner
+    (1, 64, 384, 16, None, jnp.bfloat16, 0.0),    # bf16 x
+    (2, 48, 256, 16, None, jnp.float32, 1.0),     # a nonzero h0
+])
+def test_ssm_kernel(B, T, Din, N, bd, dtype, h0_scale):
+    ks = jax.random.split(KEY, 7)
+    x = jax.random.normal(ks[0], (B, T, Din)).astype(dtype)
     dt = jax.nn.softplus(jax.random.normal(ks[1], (B, T, Din))) * 0.1
     A = -jnp.exp(jax.random.normal(ks[2], (Din, N)) * 0.3)
     Bm = jax.random.normal(ks[3], (B, T, N))
     Cm = jax.random.normal(ks[4], (B, T, N))
     D = jax.random.normal(ks[5], (Din,))
-    h0 = jnp.zeros((B, Din, N))
+    h0 = h0_scale * jax.random.normal(ks[6], (B, Din, N))
     y_ref, h_ref = ref.ssm_sequential(x, dt, A, Bm, Cm, D, h0)
     y, hT = ssm_scan(x, dt, A, Bm, Cm, D, h0, d_block=bd, interpret=True)
-    assert float(jnp.max(jnp.abs(y - y_ref))) < 1e-4
+    assert y.shape == y_ref.shape and y.dtype == dtype
+    assert hT.shape == (B, Din, N) and hT.dtype == jnp.float32
+    # bf16 y: the two fp32 results may round to neighbouring bf16 values
+    y_tol = 1e-4 if dtype == jnp.float32 else \
+        2**-7 * float(jnp.max(jnp.abs(y_ref.astype(jnp.float32))))
+    assert float(jnp.max(jnp.abs(y.astype(jnp.float32)
+                                 - y_ref.astype(jnp.float32)))) < y_tol
     assert float(jnp.max(jnp.abs(hT - h_ref))) < 1e-4
 
 

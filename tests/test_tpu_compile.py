@@ -11,7 +11,9 @@ The topology is described inside a fixture, never at import: one process
 at a time may load the TPU library, and every pytest worker imports this
 file.
 """
+import dataclasses
 import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +26,7 @@ from repro.launch.hlo_analysis import summarize_cost
 from repro.models import transformer as T
 
 V5E_HBM_BYTES = 16 * 2**30
+SERVE_BENCH = Path(__file__).resolve().parents[1] / "benchmarks" / "serve"
 
 
 @pytest.fixture(scope="module")
@@ -119,10 +122,12 @@ def test_rwkv6_scan_rwkv6_widths(one_chip):
         _sds(one_chip, (B, H, K, K), jnp.float32)))
 
 
-def test_ssm_scan_jamba_widths(one_chip):
+@pytest.mark.parametrize("S", [8, 128, 2048])
+@pytest.mark.parametrize("arch", ["jamba2-3b", "jamba-1.5-large-398b"])
+def test_ssm_scan_jamba_widths(one_chip, arch, S):
     from repro.kernels.ssm_scan import ssm_scan
-    cfg = get_config("jamba-1.5-large-398b")
-    B, S, Din, N = 1, 256, cfg.d_inner, cfg.mamba_d_state
+    cfg = get_config(arch)
+    B, Din, N = 1, cfg.d_inner, cfg.mamba_d_state
     f32 = jnp.float32
     _assert_kernel(_compile(
         ssm_scan, _sds(one_chip, (B, S, Din)), _sds(one_chip, (B, S, Din), f32),
@@ -145,6 +150,38 @@ def test_jamba2_prefill_step_full_width(one_chip):
     _assert_kernel(compiled)
     assert summarize_cost(compiled)["memory"]["peak_bytes_per_device"] \
         < V5E_HBM_BYTES
+
+
+def test_jamba2_prefill_runs_one_scan_kernel_per_mamba_layer(one_chip,
+                                                             monkeypatch):
+    """``jamba2-3b``'s prefill at a reduced width, compiled for the chip.
+    The chip's trace names a device op by its HLO instruction; the
+    instructions that the benchmark's scan readers take for the kernel
+    (``counts_ssm.kernel_events``) are all the kernel's own custom call,
+    one per Mamba position of the layer period, which runs once per
+    repeat: one call per Mamba layer and prefill."""
+    monkeypatch.syspath_prepend(str(SERVE_BENCH))
+    import counts_ssm
+    from trace_reduce import Ev, Trace
+    cfg = dataclasses.replace(get_config("jamba2-3b"), d_model=256,
+                              n_heads=2, d_ff=512, vocab_size=512,
+                              mamba_dt_rank=16)
+    params = _on(one_chip, T.abstract_params(cfg))
+    toks = _sds(one_chip, (1, 128), jnp.int32)
+    lens = _sds(one_chip, (1,), jnp.int32)
+    with ops.default_impl("pallas"):
+        compiled = _compile(lambda p, t, n: T.prefill(cfg, p, t, n),
+                            params, toks, lens)
+    lines = [ln.strip() for ln in compiled.as_text().splitlines()]
+    trace = Trace({"/device:TPU:0": [Ev(ln, i, i + 1)
+                                     for i, ln in enumerate(lines)]}, [])
+    kernel = [e.name for e in counts_ssm.kernel_events(trace)]
+    assert kernel and all(" custom-call(" in n
+                          and 'custom_call_target="tpu_custom_call"' in n
+                          for n in kernel)
+    (period, repeat), = cfg.groups
+    assert len(kernel) == sum(spec.kind == "mamba" for spec in period)
+    assert repeat * len(kernel) == 26
 
 
 def test_jamba2_append_decode_step_full_width(one_chip):
